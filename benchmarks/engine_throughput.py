@@ -14,19 +14,20 @@ per-tick tuple counts before timing anything — the throughput numbers
 cannot silently diverge from the correctness of the fused semantics.
 
 The multi-device axis (``results["devices"]``) times the sharded plane
-at several forced host-device counts.  jax locks its device count at
-first backend init, so each count runs in a subprocess (``python -m
-benchmarks.engine_throughput --cell-devices D``); the child asserts
-sharded-vs-jax count identity before timing and prints one JSON line.
+at several device counts, all in this one process (``sharded_plane(d)``
+over ``jax.devices()[:d]``): a chip belongs to one process, so a child
+process could not reach it.  Each count first asserts sharded-vs-jax
+count identity; a count above the visible devices is reported as not
+run.  On a CPU host, ``XLA_FLAGS=--xla_force_host_platform_device_count``
+(``launch.mesh.force_host_device_count``, set before jax initializes)
+provides the devices.
 """
 from __future__ import annotations
 
 import json
 import os
-import re
-import subprocess
-import sys
 
+import jax
 import numpy as np
 
 from repro.streaming import (EngineConfig, ReplaySource, StreamingEngine,
@@ -89,54 +90,17 @@ def _assert_counts_equal(plane: str, batch: int, pool: np.ndarray,
             f"fused/per-tick processed totals diverged on {plane}")
 
 
-def _device_cell(d: int, batch: int, warm: int, ticks: int) -> dict:
-    """Run one device count in a subprocess (forced host devices must be
-    set before jax initializes its backend, which this parent process
-    has already done)."""
-    env = {**os.environ, "PYTHONPATH": os.path.join(ROOT, "src")}
-    flags = re.sub(r"--xla_force_host_platform_device_count=\d+\s*", "",
-                   env.get("XLA_FLAGS", "")).strip()
-    env["XLA_FLAGS"] = \
-        f"{flags} --xla_force_host_platform_device_count={d}".strip()
-    cmd = [sys.executable, "-m", "benchmarks.engine_throughput",
-           "--cell-devices", str(d), "--batch", str(batch),
-           "--warm", str(warm), "--ticks", str(ticks)]
-    res = subprocess.run(cmd, env=env, cwd=ROOT, capture_output=True,
-                         text=True, timeout=1800)
-    if res.returncode != 0:
-        raise RuntimeError(f"devices={d} cell failed:\n"
-                           f"{res.stdout}\n{res.stderr}")
-    return json.loads(res.stdout.strip().splitlines()[-1])
-
-
-def _cell_main(argv=None) -> None:
-    """Child entry: one sharded measurement at the forced device count.
+def _device_cell(d: int, batch: int, pool: np.ndarray, warm: int,
+                 ticks: int, check_ticks: int = 12) -> dict:
+    """One sharded measurement at ``d`` devices.
 
     Asserts count identity against the single-device jax fused plane
-    *before* timing, then prints one JSON result line to stdout."""
-    import argparse
-    ap = argparse.ArgumentParser()
-    ap.add_argument("--cell-devices", type=int, required=True)
-    ap.add_argument("--batch", type=int, default=1 << 17)
-    ap.add_argument("--warm", type=int, default=40)
-    ap.add_argument("--ticks", type=int, default=24)
-    ap.add_argument("--check-ticks", type=int, default=12)
-    args = ap.parse_args(argv)
-    d = args.cell_devices
-    from repro.launch.mesh import force_host_device_count
-    force_host_device_count(d)   # idempotent when the parent set the env
-    import jax
-    if len(jax.devices()) < d:
-        raise RuntimeError(f"requested {d} devices, jax sees "
-                           f"{len(jax.devices())}")
-    pool = TwitterLikeSource(seed=0).sample_points(1 << 20)
-    # counts identity before timing: same stream through the jax fused
-    # plane and the sharded fused plane must inject identical per-tick
-    # counts and matching processed totals (spans a rebalance round)
-    a = _engine("jax", args.batch, pool)
-    a.run_fused(args.check_ticks, window=WINDOW)
-    b = _engine("sharded", args.batch, pool, devices=d)
-    b.run_fused(args.check_ticks, window=WINDOW)
+    *before* timing: the same stream must inject identical per-tick
+    counts and matching processed totals (spans a rebalance round)."""
+    a = _engine("jax", batch, pool)
+    a.run_fused(check_ticks, window=WINDOW)
+    b = _engine("sharded", batch, pool, devices=d)
+    b.run_fused(check_ticks, window=WINDOW)
     if a.metrics.injected != b.metrics.injected:
         raise AssertionError(
             f"sharded/jax injected counts diverged at devices={d}: "
@@ -145,10 +109,10 @@ def _cell_main(argv=None) -> None:
                        rtol=1e-3, atol=1e-6):
         raise AssertionError(
             f"sharded/jax processed totals diverged at devices={d}")
-    evps = _events_per_s("sharded", args.batch, pool, True,
-                         args.warm, args.ticks, devices=d)
-    print(json.dumps({"devices": d, "batch": args.batch,
-                      "sharded_fused_evps": evps, "counts_equal": True}))
+    evps = _events_per_s("sharded", batch, pool, True, warm, ticks,
+                         devices=d)
+    return {"devices": d, "batch": batch, "sharded_fused_evps": evps,
+            "counts_equal": True}
 
 
 def run(smoke: bool = False) -> dict:
@@ -176,14 +140,21 @@ def run(smoke: bool = False) -> dict:
              f"{row['fused_jax_vs_pertick_jax']:.2f}x "
              f"vs_pertick_numpy={row['fused_jax_vs_pertick_numpy']:.2f}x")
         rows.append(row)
-    # multi-device axis: sharded-plane fused throughput vs forced host
-    # device count, at the largest batch (subprocess per count; each
-    # child asserts count identity against jax fused before timing)
+    # multi-device axis: sharded-plane fused throughput vs device count,
+    # at the largest batch, in this process; counts above the visible
+    # devices are recorded as not run
     batch = sizes[-1]
     base_evps = rows[-1]["jax_fused_evps"]
+    visible = len(jax.devices())
     dev_rows = []
     for d in ((1, 2) if smoke else (1, 2, 4, 8)):
-        cell = _device_cell(d, batch, warm, ticks)
+        if d > visible:
+            dev_rows.append({"devices": d, "batch": batch,
+                             "not_run": f"{visible} devices visible"})
+            print(f"# engine/sharded/devices={d}/batch={batch}: not run, "
+                  f"{visible} devices visible")
+            continue
+        cell = _device_cell(d, batch, pool, warm, ticks)
         cell["speedup_vs_jax_fused"] = cell["sharded_fused_evps"] / base_evps
         emit(f"engine/sharded/devices={d}/batch={batch}",
              1e6 / cell["sharded_fused_evps"],
@@ -200,7 +171,3 @@ def run(smoke: bool = False) -> dict:
         with open(OUT_JSON, "w") as f:
             json.dump(result, f, indent=1)
     return result
-
-
-if __name__ == "__main__":
-    _cell_main()

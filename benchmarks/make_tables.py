@@ -128,7 +128,8 @@ def sharded_table(path="BENCH_engine.json"):
     single-device jax fused plane, and scaling efficiency (speedup/D
     relative to the D=1 sharded cell)."""
     rec = json.load(open(path))
-    rows = rec.get("devices") or []
+    # counts above the devices the run could see are recorded as not run
+    rows = [r for r in rec.get("devices") or [] if "not_run" not in r]
     if not rows:
         print(f"no devices axis in {path}; rerun "
               f"`python -m benchmarks.run --only engine`")

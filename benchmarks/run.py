@@ -43,6 +43,8 @@ def main() -> None:
                     help="export telemetry traces (JSONL + Perfetto) for "
                          "every experiment cell into DIR")
     args = ap.parse_args()
+    from repro.launch.mesh import use_compile_cache
+    use_compile_cache()
     from . import (capability, common, control_plane, dataplane, elasticity,
                    engine_throughput, geo, hotspots, kernels, overheads,
                    pubsub, queries_mixed, roofline, stats_network,

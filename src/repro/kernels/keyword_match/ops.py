@@ -1,20 +1,13 @@
-"""Public jit'd wrapper for the keyword-match kernel: padding, layout
-transform (entity-major → coordinate/bucket-major), output slicing."""
+"""Public jit'd wrapper for the keyword-match kernel: padding and
+output slicing (the kernel takes both entity- and bucket-major layouts
+itself)."""
 import functools
 
 import jax
 import jax.numpy as jnp
 
+from ..spatial_match.ops import RECT_PAD
 from .keyword_match import TB, TN, TQ, keyword_match_kernel
-
-
-def _pad_to(x, mult, axis, fill):
-    pad = (-x.shape[axis]) % mult
-    if pad == 0:
-        return x
-    widths = [(0, 0)] * x.ndim
-    widths[axis] = (0, pad)
-    return jnp.pad(x, widths, constant_values=fill)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -29,16 +22,13 @@ def keyword_match(points, pt_masks, rects, sub_masks, *,
     regardless of their (zero = wildcard) mask padding; the bucket axis
     is zero-padded, which adds no miss terms."""
     n, q = points.shape[0], rects.shape[0]
-    pts_t = _pad_to(points.T.astype(jnp.float32), TN, 1, jnp.inf)
-    pm_t = _pad_to(_pad_to(pt_masks.T.astype(jnp.float32), TB, 0, 0.0),
-                   TN, 1, 0.0)
-    rect_pad = jnp.array([jnp.inf, jnp.inf, -jnp.inf, -jnp.inf], jnp.float32)
-    rt = rects.T.astype(jnp.float32)
-    pad = (-q) % TQ
-    if pad:
-        rt = jnp.concatenate([rt, jnp.tile(rect_pad[:, None], (1, pad))], 1)
-    sm_t = _pad_to(_pad_to(sub_masks.T.astype(jnp.float32), TB, 0, 0.0),
-                   TQ, 1, 0.0)
-    pcnt, qcnt = keyword_match_kernel(pts_t, pm_t, rt, sm_t,
-                                      interpret=interpret)
-    return pcnt[:n].astype(jnp.int32), qcnt[:q].astype(jnp.int32)
+    pn, pq = (-n) % TN, (-q) % TQ
+    pb = (-pt_masks.shape[1]) % TB
+    pts = jnp.pad(points.astype(jnp.float32), ((0, pn), (0, 0)),
+                  constant_values=jnp.inf)
+    pm = jnp.pad(pt_masks.astype(jnp.float32), ((0, pn), (0, pb)))
+    pad = jnp.tile(jnp.asarray(RECT_PAD, jnp.float32), (pq, 1))
+    rts = jnp.concatenate([rects.astype(jnp.float32), pad], 0)
+    sm = jnp.pad(sub_masks.astype(jnp.float32), ((0, pq), (0, pb)))
+    pcnt, qcnt = keyword_match_kernel(pts, pm, rts, sm, interpret=interpret)
+    return pcnt[0, :n].astype(jnp.int32), qcnt[0, :q].astype(jnp.int32)

@@ -1,5 +1,6 @@
-"""Public jit'd wrapper for the spatial-match kernel: padding, layout
-transform (entity-major → coordinate-major), and output slicing."""
+"""Public jit'd wrapper for the spatial-match kernel: padding and
+output slicing (the kernel takes both entity- and coordinate-major
+layouts itself)."""
 import functools
 
 import jax
@@ -7,14 +8,8 @@ import jax.numpy as jnp
 
 from .spatial_match import TN, TQ, spatial_match_kernel
 
-
-def _pad_to(x, mult, axis, fill):
-    pad = (-x.shape[axis]) % mult
-    if pad == 0:
-        return x
-    widths = [(0, 0)] * x.ndim
-    widths[axis] = (0, pad)
-    return jnp.pad(x, widths, constant_values=fill)
+# empty padded rect: x0 = +inf, x1 = -inf never contains anything
+RECT_PAD = (jnp.inf, jnp.inf, -jnp.inf, -jnp.inf)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -25,12 +20,9 @@ def spatial_match(points, rects, *, interpret: bool = False):
     Padding points at +inf and rects as empty boxes keeps the counts
     exact for the real entries."""
     n, q = points.shape[0], rects.shape[0]
-    pts_t = _pad_to(points.T.astype(jnp.float32), TN, 1, jnp.inf)
-    # empty padded rects: x0 = +inf, x1 = -inf never contain anything
-    rect_pad = jnp.array([jnp.inf, jnp.inf, -jnp.inf, -jnp.inf], jnp.float32)
-    rt = rects.T.astype(jnp.float32)
-    pad = (-q) % TQ
-    if pad:
-        rt = jnp.concatenate([rt, jnp.tile(rect_pad[:, None], (1, pad))], 1)
-    pcnt, qcnt = spatial_match_kernel(pts_t, rt, interpret=interpret)
-    return pcnt[:n].astype(jnp.int32), qcnt[:q].astype(jnp.int32)
+    pts = jnp.pad(points.astype(jnp.float32), ((0, (-n) % TN), (0, 0)),
+                  constant_values=jnp.inf)
+    pad = jnp.tile(jnp.asarray(RECT_PAD, jnp.float32), ((-q) % TQ, 1))
+    rts = jnp.concatenate([rects.astype(jnp.float32), pad], 0)
+    pcnt, qcnt = spatial_match_kernel(pts, rts, interpret=interpret)
+    return pcnt[0, :n].astype(jnp.int32), qcnt[0, :q].astype(jnp.int32)

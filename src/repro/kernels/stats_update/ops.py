@@ -20,7 +20,7 @@ import jax
 import jax.numpy as jnp
 
 from .ref import C_N, C_Q, C_SPAN, N, NUM_CH, PRESPANQ, Q, R, SPANQ
-from .stats_update import P_TILE, stats_update_kernel
+from .stats_update import LANES, P_TILE, stats_update_kernel
 
 __all__ = ["close_round", "close_round_inputs", "close_round_xla",
            "blocked_cumsum", "IN_CH", "OUT_CH", "NUM_CH"]
@@ -31,7 +31,7 @@ def close_round(bank, *, decay: float = 0.5, interpret: bool = False):
     """Algorithm 2 for one stats bank (NUM_CH, P, G1); any P/G1."""
     _, p, g1 = bank.shape
     pp = (-p) % P_TILE
-    pg = (-g1) % 128
+    pg = (-g1) % LANES
     padded = jnp.pad(bank.astype(jnp.float32), ((0, 0), (0, pp), (0, pg)))
     out = stats_update_kernel(padded, decay=decay, interpret=interpret)
     return out[:, :p, :g1]

@@ -36,6 +36,25 @@ def force_host_device_count(n: int, env: str | None = None) -> str:
     return os.environ["XLA_FLAGS"]
 
 
+def use_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache at a fixed path.
+
+    When ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it itself and
+    this sets nothing.  Otherwise the cache goes to ``.jax_cache`` at the
+    repository root: the path is part of the cache key, so it must not
+    move between runs.  Call it from a script's ``main`` only — never at
+    import, and never from tests.  Returns the directory in use.
+    """
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    root = os.path.abspath(os.path.join(os.path.dirname(__file__),
+                                        *[os.pardir] * 3))
+    path = os.path.join(root, ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
+
+
 def streaming_mesh(devices: int | None = None):
     """1-D ``("machines",)`` mesh for the sharded streaming data plane.
 
@@ -57,13 +76,8 @@ def streaming_mesh(devices: int | None = None):
 
 
 def _mesh(shape, axes):
-    # jax.sharding.AxisType landed after 0.4.x; Auto is the default there
-    # anyway, so omit the kwarg on older versions.
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return jax.make_mesh(tuple(shape), tuple(axes))
     return jax.make_mesh(tuple(shape), tuple(axes),
-                         axis_types=(axis_type.Auto,) * len(axes))
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -694,11 +694,8 @@ class StreamingEngine:
         tcfg = self.cfg.telemetry
         if tcfg is None or not tcfg.jax_profiler_dir:
             return contextlib.nullcontext()
-        try:
-            import jax
-            return jax.profiler.trace(tcfg.jax_profiler_dir)
-        except Exception:
-            return contextlib.nullcontext()
+        import jax
+        return jax.profiler.trace(tcfg.jax_profiler_dir)
 
     def step(self) -> None:
         with activate(self.tracer):

@@ -132,14 +132,12 @@ class ShardedJaxPlane(JaxPlane):
         super().__init__()
         from ..launch.mesh import streaming_mesh
         jax = self._jax
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import NamedSharding, PartitionSpec
         self._mesh = streaming_mesh(devices)
         self._d = int(self._mesh.devices.size)
         self._Pspec = PartitionSpec
         self._shard = NamedSharding(self._mesh, PartitionSpec("machines"))
         self._repl = NamedSharding(self._mesh, PartitionSpec())
-        self._shard_map = shard_map
         self._swindow_cache: dict = {}
         # chained-window upload caches: the carry the engine hands back
         # is usually the one we just returned, and alive changes only at
@@ -393,7 +391,7 @@ class ShardedJaxPlane(JaxPlane):
                     (w_, lat, util, n_, dels_w), ok.all())
 
         pm, pr = P("machines"), P()
-        fn = self._shard_map(
+        fn = jax.shard_map(
             inner, mesh=self._mesh,
             in_specs=(pm, pm, pm, pm, pm, pr, pr, pr, pr, pr, pr, pr,
                       pr, pr, pr, pr),

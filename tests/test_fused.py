@@ -2,6 +2,12 @@
 the per-tick reference loop, rebalance rounds and machine failures at
 window boundaries, store-workload rejection, and scan-window-size
 metric invariance."""
+import json
+import os
+import pathlib
+import subprocess
+import sys
+
 import numpy as np
 import pytest
 
@@ -246,6 +252,23 @@ def test_run_fused_rejects_routers_without_seam():
 
 
 def test_engine_benchmark_smoke_counts_agree():
-    bench = pytest.importorskip("benchmarks.engine_throughput")
-    res = bench.run(smoke=True)
+    pytest.importorskip("benchmarks.engine_throughput")
+    # a fresh process, so the devices axis gets two forced host devices
+    # (jax fixes its device count when its backend first starts)
+    root = pathlib.Path(__file__).resolve().parents[1]
+    child = ("import json, sys\n"
+             "from repro.launch.mesh import force_host_device_count\n"
+             "force_host_device_count(2)\n"
+             "from benchmarks import engine_throughput as bench\n"
+             "res = bench.run(smoke=True)\n"
+             "print(json.dumps(res))\n")
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               PYTHONPATH=os.pathsep.join([str(root / "src"), str(root)]))
+    out = subprocess.run([sys.executable, "-c", child], cwd=root, env=env,
+                         capture_output=True, text=True, timeout=600)
+    assert out.returncode == 0, out.stderr[-4000:]
+    res = json.loads(out.stdout.strip().splitlines()[-1])
     assert res["results"][0]["counts_equal"]
+    # the sharded axis ran at D=1 and D=2, each count-identical to jax
+    assert [r["devices"] for r in res["devices"]] == [1, 2]
+    assert all(r.get("counts_equal") for r in res["devices"])
