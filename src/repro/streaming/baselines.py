@@ -38,6 +38,7 @@ from ..core import Swarm, balancer, geometry
 from ..core.global_index import GlobalIndex
 from ..queries import QueryModel, TermHasher, TupleStore, WorkloadSpec
 from ..queries.keywords import bucket_onehot
+from ..telemetry.tracer import current as _tracer
 from .api import (NO_ROUND, EventBatch, MachineFailure, MachineJoin,
                   MachineSlow, MemoryUsage, ProbeBatch, QueryBatch,
                   RoundOutcome, RoutingDecision, TupleBatch)
@@ -367,28 +368,30 @@ class _GridRouter(_Base):
         vectorized partitions × queries overlap test, chunked so
         million-subscription pub/sub sets never materialize the full
         Q × P hit matrix."""
-        self._ensure_qres()
-        self.qres[:] = 0
-        if self.qres_kw is not None:
-            self.qres_kw[:] = 0.0
-        if not len(self.query_rects):
-            return
-        g = self.index.grid_size
         p = self.index.parts
         live = p.live_ids()
-        r0, c0, r1, c1 = geometry.rects_to_cells(self.query_rects, g)
-        lr0, lc0 = p.r0[live][None, :], p.c0[live][None, :]
-        lr1, lc1 = p.r1[live][None, :], p.c1[live][None, :]
-        for lo in range(0, len(self.query_rects), self._BULK_CHUNK):
-            hi = min(lo + self._BULK_CHUNK, len(self.query_rects))
-            hit = geometry.boxes_overlap(
-                r0[lo:hi, None], c0[lo:hi, None],
-                r1[lo:hi, None], c1[lo:hi, None], lr0, lc0, lr1, lc1)
-            self.qres[live] += hit.sum(0)
+        with _tracer().span("reindex_queries", queries=len(self.query_rects),
+                            live=len(live)):
+            self._ensure_qres()
+            self.qres[:] = 0
             if self.qres_kw is not None:
-                qi, li = np.nonzero(hit)
-                np.add.at(self.qres_kw,
-                          (live[li], self.sub_pivots[lo:hi][qi]), 1.0)
+                self.qres_kw[:] = 0.0
+            if not len(self.query_rects):
+                return
+            g = self.index.grid_size
+            r0, c0, r1, c1 = geometry.rects_to_cells(self.query_rects, g)
+            lr0, lc0 = p.r0[live][None, :], p.c0[live][None, :]
+            lr1, lc1 = p.r1[live][None, :], p.c1[live][None, :]
+            for lo in range(0, len(self.query_rects), self._BULK_CHUNK):
+                hi = min(lo + self._BULK_CHUNK, len(self.query_rects))
+                hit = geometry.boxes_overlap(
+                    r0[lo:hi, None], c0[lo:hi, None],
+                    r1[lo:hi, None], c1[lo:hi, None], lr0, lc0, lr1, lc1)
+                self.qres[live] += hit.sum(0)
+                if self.qres_kw is not None:
+                    qi, li = np.nonzero(hit)
+                    np.add.at(self.qres_kw,
+                              (live[li], self.sub_pivots[lo:hi][qi]), 1.0)
 
     def _area_frac(self) -> np.ndarray:
         """Partition area as a fraction of the space, per allocated pid

@@ -680,22 +680,11 @@ class StreamingEngine:
         # routers/workloads outside the fused envelope (replicated,
         # tuple stores) silently take the per-tick loop so mixed
         # sweeps complete; calling run_fused directly still raises
-        with self._profiler_hook():
-            if self.cfg.fused_window > 0 and self.fused_supported():
-                return self.run_fused(ticks, self.cfg.fused_window)
-            for _ in range(ticks):
-                self.step()
-            return self.metrics
-
-    def _profiler_hook(self):
-        """Optional ``jax.profiler`` capture around a run (device-level
-        detail beneath our spans); a no-op nullcontext otherwise."""
-        import contextlib
-        tcfg = self.cfg.telemetry
-        if tcfg is None or not tcfg.jax_profiler_dir:
-            return contextlib.nullcontext()
-        import jax
-        return jax.profiler.trace(tcfg.jax_profiler_dir)
+        if self.cfg.fused_window > 0 and self.fused_supported():
+            return self.run_fused(ticks, self.cfg.fused_window)
+        for _ in range(ticks):
+            self.step()
+        return self.metrics
 
     def step(self) -> None:
         with activate(self.tracer):
@@ -918,10 +907,12 @@ class StreamingEngine:
             # stage W ticks of candidate batches (tick-ordered, so the
             # source RNG stream matches the per-tick loop); keyword
             # workloads stage the hashed probe buckets alongside
-            batches = [self.stream.tuples(b, tt) for tt in range(t, stop)]
-            xy = np.stack([bt.xy for bt in batches])
-            kw_stack = (np.stack([bt.buckets for bt in batches])
-                        if batches[0].buckets is not None else None)
+            with tr.span("window_stage", ticks=w):
+                batches = [self.stream.tuples(b, tt)
+                           for tt in range(t, stop)]
+                xy = np.stack([bt.xy for bt in batches])
+                kw_stack = (np.stack([bt.buckets for bt in batches])
+                            if batches[0].buckets is not None else None)
             self._fused_refresh(plane)
             # ingest-tier cell ids: forwarded only to planes that want
             # them, and only when every staged batch carries ids for
@@ -1001,16 +992,19 @@ class StreamingEngine:
                 # stats bank, run the planner round, patch the last
                 # tick's round metrics in place (step() records them on
                 # the same tick row)
-                self._fused_sync_collectors()
-                outcome = router.on_round(last)
-                if tr.enabled and outcome.decision_record is not None:
-                    tr.record_decision(outcome.decision_record, tick=last)
-                    if outcome.transfers:
-                        tr.instant("rebalance", tick=last,
-                                   transfers=len(outcome.transfers),
-                                   moved_queries=outcome.moved_queries,
-                                   migration_bytes=outcome.migration_bytes)
-                rw, rm, rt, rp = self._settle_outcome(outcome, t=last)
+                with tr.span("router_round", tick=last):
+                    self._fused_sync_collectors()
+                    outcome = router.on_round(last)
+                    if tr.enabled and outcome.decision_record is not None:
+                        tr.record_decision(outcome.decision_record,
+                                           tick=last)
+                        if outcome.transfers:
+                            tr.instant(
+                                "rebalance", tick=last,
+                                transfers=len(outcome.transfers),
+                                moved_queries=outcome.moved_queries,
+                                migration_bytes=outcome.migration_bytes)
+                    rw, rm, rt, rp = self._settle_outcome(outcome, t=last)
                 if self.san is not None:
                     self.san.check_round(self, outcome)
                 # zero-delay transfer shares completed inside the settle
@@ -1219,19 +1213,23 @@ class StreamingEngine:
         router snapshots are diffed so a rebalance becomes a scatter
         update of the changed grid cells / owner rows; only a capacity
         growth forces a rebuild."""
-        host = self.router.fused_host_state()
-        f = self._fused
-        if f is None or f["plane"] is not plane:
-            self._fused = {"plane": plane, "host": host,
-                           "state": plane.make_state(host)}
-            return
-        updates = f["host"].diff(host)
-        if updates is None:                      # capacity grew: rebuild
-            self._fused_sync_collectors()        # (banks change shape)
-            f["state"] = plane.make_state(host)
-        elif updates:
-            f["state"] = plane.scatter_update(f["state"], updates)
-        f["host"] = host
+        with self.tracer.span("state_refresh") as sp:
+            host = self.router.fused_host_state()
+            f = self._fused
+            if f is None or f["plane"] is not plane:
+                self._fused = {"plane": plane, "host": host,
+                               "state": plane.make_state(host)}
+                sp.set(rebuilt=True)
+                return
+            updates = f["host"].diff(host)
+            if updates is None:                  # capacity grew: rebuild
+                self._fused_sync_collectors()    # (banks change shape)
+                f["state"] = plane.make_state(host)
+                sp.set(rebuilt=True)
+            elif updates:
+                f["state"] = plane.scatter_update(f["state"], updates)
+                sp.set(patched=sum(len(v) for _, v in updates.values()))
+            f["host"] = host
 
     def _fused_sync_collectors(self) -> None:
         """Drain device-accumulated N′ collector deltas into the host
@@ -1239,10 +1237,13 @@ class StreamingEngine:
         f = self._fused
         if not f or not f["host"].track_stats:
             return
-        cnr, cnc = f["plane"].collector_banks(f["state"])
-        if cnr.any() or cnc.any():
-            self.router.fused_absorb(cnr, cnc)
-            f["state"] = f["plane"].reset_collectors(f["state"])
+        with self.tracer.span("collector_drain") as sp:
+            cnr, cnc = f["plane"].collector_banks(f["state"])
+            drained = bool(cnr.any() or cnc.any())
+            if drained:
+                self.router.fused_absorb(cnr, cnc)
+                f["state"] = f["plane"].reset_collectors(f["state"])
+            sp.set(drained=drained)
 
     def _reshard_outcome(self, outcome) -> None:
         """Physically re-home a round/recovery outcome's transferred

@@ -484,6 +484,42 @@ def _pad_pow2(n: int) -> int:
     return 1 << max(n - 1, 1).bit_length() if n > 2 else max(n, 1)
 
 
+_BACKEND_COMPILE = "/jax/core/compile/backend_compile_duration"
+_builds = {"watching": False, "cached": False, "total": 0}
+
+
+def _watch_builds() -> None:
+    """Forward every program JAX builds to the active tracer, once per
+    process: a ``program_build`` span at the build's measured duration
+    (args ``fun``, XLA's module name, and ``cached``, read from the
+    persistent compile cache) under whatever span asked for the
+    program, and the ``programs_built`` counter (this process's
+    total)."""
+    if _builds["watching"]:
+        return
+    import jax.monitoring as mon
+
+    def on_event(event, **kw):
+        if event == "/jax/compilation_cache/cache_hits":
+            _builds["cached"] = True
+
+    def on_duration(event, secs, **kw):
+        if event != _BACKEND_COMPILE:
+            return
+        cached, _builds["cached"] = _builds["cached"], False
+        _builds["total"] += 1
+        tr = _tracer()
+        if tr.enabled:
+            t1 = tr.now()
+            tr.emit_span("program_build", t1 - int(secs * 1e9), t1,
+                         fun=str(kw.get("fun_name", "?")), cached=cached)
+            tr.counter("programs_built", _builds["total"], t0=t1)
+
+    mon.register_event_listener(on_event)
+    mon.register_event_duration_secs_listener(on_duration)
+    _builds["watching"] = True
+
+
 def _pad64(n: int) -> int:
     """Round up to a multiple of 64 — finer shape buckets than pow2 for
     the live-partition subset (its size drifts by a few per round, so
@@ -534,6 +570,7 @@ class JaxPlane(DataPlane):
         import jax.numpy as jnp
         self._jax, self._jnp = jax, jnp
         self._on_tpu = jax.default_backend() == "tpu"
+        _watch_builds()
         # input-output buffer aliasing for the resident fused state in
         # the single-tick step path (run_window deliberately does not
         # donate — declined windows roll back to the pre-window state);
@@ -975,17 +1012,20 @@ class JaxPlane(DataPlane):
         fn = self._step_cache.get(key)
         compiling = fn is None
         if compiling:
+            # the bound method itself is jitted, with its options
+            # static, so XLA names the module after it (``jit__step_fn``;
+            # a jitted ``functools.partial`` shows as ``jit__unknown``)
             if keyword:
-                fn = self._jax.jit(
-                    functools.partial(self._kw_step_fn,
-                                      track_stats=track_stats),
-                    donate_argnums=self._donate_step)
+                fn = functools.partial(self._jax.jit(
+                    self._kw_step_fn, static_argnames=("track_stats",),
+                    donate_argnums=self._donate_step),
+                    track_stats=track_stats)
             else:
-                fn = self._jax.jit(
-                    functools.partial(self._step_fn,
-                                      track_stats=track_stats,
-                                      tuple_driven=cp.tuple_driven),
-                    donate_argnums=self._donate_step)
+                fn = functools.partial(self._jax.jit(
+                    self._step_fn,
+                    static_argnames=("track_stats", "tuple_driven"),
+                    donate_argnums=self._donate_step),
+                    track_stats=track_stats, tuple_driven=cp.tuple_driven)
             self._step_cache[key] = fn
         if keyword:
             from ..queries.keywords import bucket_onehot
@@ -1165,19 +1205,21 @@ class JaxPlane(DataPlane):
         # keyword workloads a second (cell, term-bucket) histogram
         # rides along (W, G²·(T+1)): term filtering factors through it
         # exactly like spatial routing factors through the cell counts.
+        tr = _tracer()
         hists = np.zeros((wp, g * g), np.float32)
         t1 = int(state.qres_kw.shape[1]) if keyword else 0
         kwh = np.zeros((wp, g * g * t1), np.float32) if keyword else None
-        for i in range(w):
-            row, col = geometry.points_to_cells(
-                np.asarray(xy_stack[i], np.float32), g)
-            cell = row.astype(np.int64) * g + col
-            hists[i] = np.bincount(cell, minlength=g * g)
-            if keyword:
-                ids = np.asarray(kw_stack[i], np.int64)
-                flat = cell[:, None] * t1 + ids
-                kwh[i] = np.bincount(flat[ids >= 0].reshape(-1),
-                                     minlength=g * g * t1)
+        with tr.span("window_bin", ticks=w):
+            for i in range(w):
+                row, col = geometry.points_to_cells(
+                    np.asarray(xy_stack[i], np.float32), g)
+                cell = row.astype(np.int64) * g + col
+                hists[i] = np.bincount(cell, minlength=g * g)
+                if keyword:
+                    ids = np.asarray(kw_stack[i], np.int64)
+                    flat = cell[:, None] * t1 + ids
+                    kwh[i] = np.bincount(flat[ids >= 0].reshape(-1),
+                                         minlength=g * g * t1)
         # allocated-id prefix, in 64-row buckets like close_round (the
         # prefix drifts by a few ids per round; full capacity only as
         # the fallback when no prefix was provided)
@@ -1191,23 +1233,26 @@ class JaxPlane(DataPlane):
             # deliberately NOT donated: a declined window (ok=False)
             # rolls back to the pre-window state, which must stay alive
             # — the mutable part (collector banks) is small
-            fn = self._jax.jit(
-                functools.partial(self._window_fn,
-                                  track_stats=fp.track_stats,
-                                  tuple_driven=cp.tuple_driven,
-                                  keyword=keyword, batch=b,
-                                  p_used=p_used))
+            fn = functools.partial(
+                self._jax.jit(self._window_fn, static_argnames=(
+                    "track_stats", "tuple_driven", "keyword", "batch",
+                    "p_used")),
+                track_stats=fp.track_stats, tuple_driven=cp.tuple_driven,
+                keyword=keyword, batch=b, p_used=p_used)
             self._window_cache[key] = fn
-        ep = tuple(self._sc(v) for v in (fp.cap_units, fp.lambda_max,
-                                         fp.bp_high, fp.bp_dec, fp.bp_inc)
-                   ) + (self._upload.get(np.int32(w)),)
-        carry_dev = (jnp.asarray(np.asarray(carry.queue_units, np.float32)),
-                     jnp.asarray(np.asarray(carry.queue_tuples, np.float32)),
-                     jnp.float32(carry.lam_bp))
-        args = (state, carry_dev, jnp.asarray(hists),
-                None if kwh is None else jnp.asarray(kwh),
-                self._cost_scalars(cp), ep, self._dev(fp.alive, np.float32))
-        tr = _tracer()
+        with tr.span("window_upload"):
+            ep = tuple(self._sc(v) for v in (fp.cap_units, fp.lambda_max,
+                                             fp.bp_high, fp.bp_dec,
+                                             fp.bp_inc)
+                       ) + (self._upload.get(np.int32(w)),)
+            carry_dev = (
+                jnp.asarray(np.asarray(carry.queue_units, np.float32)),
+                jnp.asarray(np.asarray(carry.queue_tuples, np.float32)),
+                jnp.float32(carry.lam_bp))
+            args = (state, carry_dev, jnp.asarray(hists),
+                    None if kwh is None else jnp.asarray(kwh),
+                    self._cost_scalars(cp), ep,
+                    self._dev(fp.alive, np.float32))
         if tr.enabled:
             # first call on a fresh cache key pays XLA compilation —
             # split it from steady-state dispatch, and fence with
@@ -1221,16 +1266,17 @@ class JaxPlane(DataPlane):
                 self._jax.block_until_ready((state, qu, qt, outs, ok))
         else:
             state, (qu, qt, lam_bp), outs, ok = fn(*args)
-        return (state,
-                EngineCarry(np.asarray(qu, np.float64),
-                            np.asarray(qt, np.float64), float(lam_bp)),
-                FusedOutputs(np.asarray(outs[0], np.float64)[:w],
-                             np.asarray(outs[1], np.float64)[:w],
-                             np.asarray(outs[2], np.float64)[:w],
-                             np.asarray(outs[3], np.int64)[:w],
-                             (np.asarray(outs[4], np.float64)[:w]
-                              if keyword else None)),
-                bool(ok))
+        with tr.span("window_readback"):
+            return (state,
+                    EngineCarry(np.asarray(qu, np.float64),
+                                np.asarray(qt, np.float64), float(lam_bp)),
+                    FusedOutputs(np.asarray(outs[0], np.float64)[:w],
+                                 np.asarray(outs[1], np.float64)[:w],
+                                 np.asarray(outs[2], np.float64)[:w],
+                                 np.asarray(outs[3], np.int64)[:w],
+                                 (np.asarray(outs[4], np.float64)[:w]
+                                  if keyword else None)),
+                    bool(ok))
 
 
 # ---------------------------------------------------------------------------

@@ -413,43 +413,48 @@ class ShardedJaxPlane(JaxPlane):
         # host ingest tier: one contiguous chunk = one ingest worker per
         # device; batches carrying precomputed cell ids skip the
         # point→cell pass entirely
-        hists, kwh = window_histograms(xy_stack, g, devices=d, wp=wp,
-                                       cells=cells, kw_stack=kw_stack,
-                                       t1=t1)
+        tr = _tracer()
+        with tr.span("window_bin", ticks=w):
+            hists, kwh = window_histograms(xy_stack, g, devices=d, wp=wp,
+                                           cells=cells, kw_stack=kw_stack,
+                                           t1=t1)
         key = (wp, b, int(state.owner.shape[0]), s, g, len(fp.alive),
                fp.track_stats, cp.tuple_driven, keyword, t1)
         fn = self._swindow_cache.get(key)
         compiling = fn is None
         if compiling:
-            fn = jax.jit(functools.partial(
-                self._sharded_window, track_stats=fp.track_stats,
-                tuple_driven=cp.tuple_driven, keyword=keyword, batch=b))
+            fn = functools.partial(
+                jax.jit(self._sharded_window, static_argnames=(
+                    "track_stats", "tuple_driven", "keyword", "batch")),
+                track_stats=fp.track_stats, tuple_driven=cp.tuple_driven,
+                keyword=keyword, batch=b)
             self._swindow_cache[key] = fn
-        ep = tuple(self._sc(v) for v in (fp.cap_units, fp.lambda_max,
-                                         fp.bp_high, fp.bp_dec, fp.bp_inc)
-                   ) + (self._upload.get(np.int32(w)),)
-        ck = (np.asarray(carry.queue_units, np.float64).tobytes(),
-              np.asarray(carry.queue_tuples, np.float64).tobytes(),
-              float(carry.lam_bp))
-        if self._carry_cache is not None and self._carry_cache[0] == ck:
-            carry_dev = self._carry_cache[1]
-        else:
-            carry_dev = (
-                self._put_r(np.asarray(carry.queue_units), np.float32),
-                self._put_r(np.asarray(carry.queue_tuples), np.float32),
-                jnp.float32(carry.lam_bp))
-        hs = jax.device_put(hists, self._shard)
-        kws = None if kwh is None else jax.device_put(kwh, self._shard)
-        ak = np.asarray(fp.alive, np.float32).tobytes()
-        alive = self._alive_cache.get(ak)
-        if alive is None:
-            if len(self._alive_cache) > 64:
-                self._alive_cache.clear()
-            alive = self._alive_cache[ak] = self._put_r(fp.alive,
-                                                        np.float32)
-        args = (state, carry_dev, hs, kws, self._cost_scalars(cp), ep,
-                alive)
-        tr = _tracer()
+        with tr.span("window_upload"):
+            ep = tuple(self._sc(v) for v in (fp.cap_units, fp.lambda_max,
+                                             fp.bp_high, fp.bp_dec,
+                                             fp.bp_inc)
+                       ) + (self._upload.get(np.int32(w)),)
+            ck = (np.asarray(carry.queue_units, np.float64).tobytes(),
+                  np.asarray(carry.queue_tuples, np.float64).tobytes(),
+                  float(carry.lam_bp))
+            if self._carry_cache is not None and self._carry_cache[0] == ck:
+                carry_dev = self._carry_cache[1]
+            else:
+                carry_dev = (
+                    self._put_r(np.asarray(carry.queue_units), np.float32),
+                    self._put_r(np.asarray(carry.queue_tuples), np.float32),
+                    jnp.float32(carry.lam_bp))
+            hs = jax.device_put(hists, self._shard)
+            kws = None if kwh is None else jax.device_put(kwh, self._shard)
+            ak = np.asarray(fp.alive, np.float32).tobytes()
+            alive = self._alive_cache.get(ak)
+            if alive is None:
+                if len(self._alive_cache) > 64:
+                    self._alive_cache.clear()
+                alive = self._alive_cache[ak] = self._put_r(fp.alive,
+                                                            np.float32)
+            args = (state, carry_dev, hs, kws, self._cost_scalars(cp), ep,
+                    alive)
         if tr.enabled:
             name = ("sharded_window_compile" if compiling
                     else "sharded_window_dispatch")
@@ -465,20 +470,21 @@ class ShardedJaxPlane(JaxPlane):
         else:
             cnr, cnc, (qu, qt, lam_bp), outs, ok = fn(*args)
         state = state._replace(cn_rows=cnr, cn_cols=cnc)
-        qu_h = np.asarray(qu, np.float64)
-        qt_h = np.asarray(qt, np.float64)
-        lam_h = float(lam_bp)
-        self._carry_cache = ((qu_h.tobytes(), qt_h.tobytes(), lam_h),
-                             (qu, qt, lam_bp))
-        return (state,
-                EngineCarry(qu_h, qt_h, lam_h),
-                FusedOutputs(np.asarray(outs[0], np.float64)[:w],
-                             np.asarray(outs[1], np.float64)[:w],
-                             np.asarray(outs[2], np.float64)[:w],
-                             np.asarray(outs[3], np.int64)[:w],
-                             (np.asarray(outs[4], np.float64)[:w]
-                              if keyword else None)),
-                bool(ok))
+        with tr.span("window_readback"):
+            qu_h = np.asarray(qu, np.float64)
+            qt_h = np.asarray(qt, np.float64)
+            lam_h = float(lam_bp)
+            self._carry_cache = ((qu_h.tobytes(), qt_h.tobytes(), lam_h),
+                                 (qu, qt, lam_bp))
+            return (state,
+                    EngineCarry(qu_h, qt_h, lam_h),
+                    FusedOutputs(np.asarray(outs[0], np.float64)[:w],
+                                 np.asarray(outs[1], np.float64)[:w],
+                                 np.asarray(outs[2], np.float64)[:w],
+                                 np.asarray(outs[3], np.int64)[:w],
+                                 (np.asarray(outs[4], np.float64)[:w]
+                                  if keyword else None)),
+                    bool(ok))
 
     # -- transfers as physical resharding ------------------------------------
     def reshard_transfers(self, state, outcome, router) -> int:

@@ -1,20 +1,39 @@
 """Tracer: nested spans, counters and instants over the streaming
 stack, with a zero-overhead disabled path.
 
-Span taxonomy (DESIGN.md §9): the control-plane timeline carries
-``tick``, ``fused_window`` (with ``fused_window_compile`` /
-``fused_window_dispatch`` children from the JAX plane), ``round_close``
-→ ``plan_round`` / ``apply_plan``, ``failover`` and ``heartbeat_scan``
-spans plus instants for FSM transitions, rebalances, membership events
-and heartbeat misses; each machine owns a track of per-tick spans and
+Span taxonomy (DESIGN.md §9), children indented under their parent:
+
+* ``tick`` (per-tick loop) → ``heartbeat_scan``, ``round_close``
+* ``fused_window`` (one fused window of W ticks)
+    → ``window_stage`` (staging the W batches, ``np.stack``)
+    → ``state_refresh`` (diff-patch of the resident device state,
+      arg ``patched``) → ``collector_drain`` on a rebuild
+    → ``window_bin`` (host cell histograms), ``window_upload``,
+      ``fused_window_compile`` | ``fused_window_dispatch`` (or the
+      ``sharded_window_*`` pair) → ``program_build``,
+      ``window_readback``
+* ``router_round`` (the fused loop's round boundary)
+    → ``collector_drain`` (arg ``drained``)
+    → ``round_close`` → ``plan_round`` / ``apply_plan``
+    → ``reindex_queries`` (args ``queries``, ``live``)
+* ``failover`` → ``plan_round`` / ``apply_plan``
+
+``program_build`` (args ``fun``, ``cached``) is recorded under whatever
+span asked for the program, with the ``programs_built`` counter;
+instants mark FSM transitions, rebalances, membership events and
+heartbeat misses; each machine owns a track of per-tick spans and
 queue/utilization counters.
 
 The zero-overhead contract: when telemetry is off the engine holds the
 :data:`NOOP` singleton, every instrumentation site is guarded by a
-single ``if tr.enabled`` attribute test (~30 ns), and the fused window
-performs **no** ``block_until_ready`` host sync it wouldn't otherwise
-do.  The enabled path buffers plain tuples in Python lists — no I/O
-until :meth:`Tracer.export`.
+single ``if tr.enabled`` attribute test (~30 ns) or opens the shared
+null span, and the fused window performs **no** ``block_until_ready``
+host sync it wouldn't otherwise do.  The enabled path buffers plain
+tuples in Python lists — no I/O until :meth:`Tracer.export` — and, once
+the process has imported ``jax.profiler``, opens a
+``jax.profiler.TraceAnnotation`` of the same name around every span
+(not around :meth:`Tracer.emit_span`'s retroactive ones), so a
+profiler capture carries the program's spans on its own clock.
 
 Spans carry ``(tick, seq, parent)`` ordering metadata alongside wall
 times, so :meth:`Tracer.signature` can render the structural span tree
@@ -23,6 +42,7 @@ across runs and data planes.
 """
 from __future__ import annotations
 
+import sys
 import time
 from dataclasses import dataclass, field
 
@@ -35,14 +55,13 @@ class TelemetryConfig:
     """Engine-facing switch (``EngineConfig.telemetry``).  ``None``
     (the default) keeps the no-op singleton; an instance turns the
     tracer on.  ``trace_dir`` makes ``experiments.run`` export JSONL +
-    Perfetto files after the run; ``jax_profiler_dir`` additionally
-    wraps the run in a ``jax.profiler.trace`` capture (device-level
-    detail beyond our spans)."""
+    Perfetto files after the run.  For device-level detail, wrap the
+    run in ``jax.profiler.trace``: the capture carries the tracer's
+    spans too."""
 
     enabled: bool = True
     trace_dir: str | None = None
     tick_spans: bool = True      # per-machine per-tick spans + counters
-    jax_profiler_dir: str | None = None
 
     def __str__(self):  # keeps Experiment labels compact & stable
         parts = [] if self.enabled else ["off"]
@@ -50,8 +69,6 @@ class TelemetryConfig:
             parts.append("trace")
         if not self.tick_spans:
             parts.append("nospans")
-        if self.jax_profiler_dir:
-            parts.append("jaxprof")
         return "telemetry(" + ",".join(parts or ["on"]) + ")"
 
 
@@ -78,11 +95,12 @@ class _Span:
     closes the span and lets instrumentation attach results via
     :meth:`set` before exit."""
 
-    __slots__ = ("_tr", "_ev")
+    __slots__ = ("_tr", "_ev", "_ann")
 
-    def __init__(self, tr, ev):
+    def __init__(self, tr, ev, ann):
         self._tr = tr
         self._ev = ev
+        self._ann = ann
 
     def set(self, **kw):
         self._ev.args.update(kw)
@@ -92,8 +110,20 @@ class _Span:
         return self
 
     def __exit__(self, *exc):
-        self._tr._close(self._ev)
+        self._tr._close(self._ev, self._ann)
         return False
+
+
+def _annotation(name: str):
+    """An open ``jax.profiler.TraceAnnotation`` named ``name``, or None
+    while the process has not imported ``jax.profiler`` (this package
+    never imports jax itself)."""
+    prof = sys.modules.get("jax.profiler")
+    if prof is None:
+        return None
+    ann = prof.TraceAnnotation(name)
+    ann.__enter__()
+    return ann
 
 
 class _NullSpan:
@@ -127,7 +157,6 @@ class Tracer:
         self._epoch = time.perf_counter_ns()
         self._seq = 0
         self._stack: list[TraceEvent] = []
-        self._counters: dict[tuple, float] = {}
 
     # -- time ---------------------------------------------------------
     def now(self) -> int:
@@ -137,16 +166,19 @@ class Tracer:
     # -- spans --------------------------------------------------------
     def span(self, name: str, *, machine: int = CONTROL, tick: int = -1,
              **args) -> _Span:
-        """Open a nested span; close it by exiting the context (or use
-        :meth:`emit_span` for already-measured intervals)."""
+        """Open a nested span, and its profiler annotation; close both
+        by exiting the context (or use :meth:`emit_span` for
+        already-measured intervals)."""
         parent = self._stack[-1].seq if self._stack else -1
         ev = TraceEvent("span", name, machine, tick, self._seq, parent,
                         self.now(), -1, dict(args) if args else {})
         self._seq += 1
         self._stack.append(ev)
-        return _Span(self, ev)
+        return _Span(self, ev, _annotation(name))
 
-    def _close(self, ev: TraceEvent):
+    def _close(self, ev: TraceEvent, ann):
+        if ann is not None:
+            ann.__exit__(None, None, None)
         ev.dur = self.now() - ev.t0
         # tolerate out-of-order exits (exceptions unwinding)
         if self._stack and self._stack[-1] is ev:
@@ -159,7 +191,8 @@ class Tracer:
                   machine: int = CONTROL, tick: int = -1, **args):
         """Record a span from explicit ``now()`` bounds — used for the
         synthetic per-machine tick spans where the work for all
-        machines happens in one vectorized host step."""
+        machines happens in one vectorized host step, and for program
+        builds timed by JAX.  Buffer only: no profiler annotation."""
         parent = self._stack[-1].seq if self._stack else -1
         self.events.append(TraceEvent(
             "span", name, machine, tick, self._seq, parent, t0,
@@ -177,16 +210,10 @@ class Tracer:
 
     def counter(self, name: str, value, *, machine: int = CONTROL,
                 tick: int = -1, t0: int | None = None):
-        v = float(value)
-        self._counters[(name, machine)] = v
         self.events.append(TraceEvent(
             "counter", name, machine, tick, self._seq, -1,
-            self.now() if t0 is None else t0, 0, {"value": v}))
+            self.now() if t0 is None else t0, 0, {"value": float(value)}))
         self._seq += 1
-
-    def gauge(self, name: str, machine: int = CONTROL) -> float | None:
-        """Last value a counter was set to (None if never set)."""
-        return self._counters.get((name, machine))
 
     def counter_series(self, name: str, machine: int = CONTROL):
         """(ticks, values) of one counter — the example's UoW timeline
@@ -255,9 +282,6 @@ class _NoopTracer:
 
     def counter(self, name, value, *, machine=CONTROL, tick=-1, t0=None):
         pass
-
-    def gauge(self, name, machine=CONTROL):
-        return None
 
     def counter_series(self, name, machine=CONTROL):
         return [], []

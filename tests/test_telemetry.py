@@ -1,12 +1,16 @@
 """Flight-recorder telemetry: zero-overhead no-op default, planner
 DecisionRecords that mirror the applied transfers exactly, same-seed
 span-tree/record determinism on both data planes, Perfetto export
-against the checked-in schema, the fused compile/dispatch split, and
-the ft-layer heartbeat/failover events."""
+against the checked-in schema, the fused compile/dispatch split, the
+fused loop's host-phase spans and their copies in a profiler capture,
+program builds as events, and the ft-layer heartbeat/failover
+events."""
 import dataclasses
+import glob
 import json
 import os
 import time
+from collections import Counter
 
 import numpy as np
 import pytest
@@ -294,6 +298,120 @@ def test_fused_run_decisions_match_per_tick(plane):
            tuple((t.m_h, t.m_l, t.action) for t in r.transfers))
           for _, r in run(_exp(plane, engine=fused)).tracer.decisions]
     assert dp == df
+
+
+# ---------------------------------------------------------------------------
+# Fused path: host-phase spans, profiler annotations, program builds
+# ---------------------------------------------------------------------------
+
+FUSED = dataclasses.replace(CFG, fused_window=4)
+
+
+def _tree(tracer) -> Counter:
+    """(span, parent span) pairs of a run, counted."""
+    by_seq = {e.seq: e for e in tracer.events}
+    return Counter((e.name, by_seq[e.parent].name if e.parent in by_seq
+                    else None) for e in tracer.events if e.kind == "span")
+
+
+class _CountingAnnotation:
+    opened = 0
+
+    def __init__(self, name, **kw):
+        type(self).opened += 1
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        return False
+
+
+def test_fused_run_spans_the_reindex_and_the_window_host_phases(
+        monkeypatch):
+    jax = pytest.importorskip("jax")
+    monkeypatch.setattr(SwarmRouter, "on_round", lambda self, tick:
+                        self._outcome(force_rebalance_round(self.swarm)))
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", _CountingAnnotation)
+    _CountingAnnotation.opened = 0
+    tr = run(_exp("jax", engine=FUSED,
+                  telemetry=TelemetryConfig(tick_spans=False))).tracer
+    tree = _tree(tr)
+    assert tree[("reindex_queries", "router_round")] >= 1
+    assert tree[("collector_drain", "router_round")] >= 1
+    windows = tree[("fused_window", None)]
+    assert windows >= 1
+    for name in ("window_stage", "state_refresh", "window_bin",
+                 "window_upload", "window_readback"):
+        assert tree[(name, "fused_window")] == windows, name
+    reindex = [e for e in tr.events if e.name == "reindex_queries"]
+    assert all(e.args["queries"] >= 400 and e.args["live"] >= M
+               for e in reindex)
+    # every span opened its annotation; emit_span's builds did not
+    opened = sum(1 for e in tr.events
+                 if e.kind == "span" and e.name != "program_build")
+    assert _CountingAnnotation.opened == opened
+
+
+def test_fused_run_with_telemetry_off_buffers_and_annotates_nothing(
+        monkeypatch):
+    jax = pytest.importorskip("jax")
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", _CountingAnnotation)
+    _CountingAnnotation.opened = 0
+    res = run(_exp("jax", engine=FUSED, telemetry=None))
+    assert res.tracer is None
+    assert NOOP.events == [] and NOOP.decisions == []
+    assert _CountingAnnotation.opened == 0
+
+
+def test_profiler_capture_holds_the_program_spans(tmp_path):
+    jax = pytest.importorskip("jax")
+    with jax.profiler.trace(str(tmp_path)):
+        tr = run(_exp("jax", engine=FUSED,
+                      telemetry=TelemetryConfig(tick_spans=False))).tracer
+    (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    host = Counter(e.name
+                   for plane in jax.profiler.ProfileData.from_file(path).planes
+                   if plane.name.startswith("/host:")
+                   for line in plane.lines for e in line.events)
+    buffered = Counter(tr.span_names())
+    for name in ("fused_window", "round_close", "reindex_queries",
+                 "router_round", "window_bin"):
+        assert buffered[name] >= 1, name
+        assert host[name] == buffered[name], name
+
+
+def test_program_build_when_n_alloc_crosses_a_bucket():
+    pytest.importorskip("jax")
+    from repro.streaming.fused import EngineCarry, FusedParams
+    from repro.streaming.planes import JaxPlane
+    router = SwarmRouter(16, 4, data_plane="jax")
+    h = router.fused_host_state()
+    pad = (0, 128 - h.capacity)            # room for two 64-row buckets
+    host = dataclasses.replace(
+        h, owner=np.pad(h.owner, pad), qres=np.pad(h.qres, pad),
+        area_frac=np.pad(h.area_frac, pad, constant_values=1.0))
+    plane = JaxPlane()
+    state = plane.make_state(host)
+    # 3 ticks of 211 events: a shape no other test builds
+    xy = np.random.default_rng(0).uniform(0, 1, (3, 211, 2))
+    carry = EngineCarry(np.zeros(4), np.zeros(4), 211.0)
+
+    def builds(n_alloc):
+        fp = FusedParams(cap_units=1e9, lambda_max=211.0, bp_high=0.8,
+                         bp_dec=0.5, bp_inc=0.1, alive=np.ones(4),
+                         track_stats=True, n_alloc=n_alloc)
+        tr = Tracer()
+        with activate(tr):
+            plane.run_window(state, router._cost_params(), fp, carry, xy)
+        return ([e for e in tr.events if e.name == "program_build"],
+                tr.counter_series("programs_built")[1])
+    builds(host.n_alloc)                   # the first bucket's program
+    (build,), (total,) = builds(65)        # the second bucket's
+    assert "_window_fn" in build.args["fun"]
+    assert isinstance(build.args["cached"], bool) and build.dur > 0
+    assert build.parent >= 0               # under the compile span
+    assert total >= 2
 
 
 # ---------------------------------------------------------------------------
