@@ -158,6 +158,9 @@ def restore_stream(directory: str, engine, step: int | None = None) -> int:
         router.qres_kw = arrays["router/qres_kw"].copy()
     if "router/sub_pivots" in arrays:
         router.sub_pivots = arrays["router/sub_pivots"].copy()
+    # the corner tables a re-index reads are derived state: bin the
+    # restored queries into them again
+    router.rebuild_query_tables()
     if "store/counts" in arrays and getattr(router, "store", None) is not None:
         router.store.counts = arrays["store/counts"].copy()
 

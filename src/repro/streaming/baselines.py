@@ -295,12 +295,6 @@ class ReplicatedRouter(_Base):
 class _GridRouter(_Base):
     """Shared machinery for grid-index routers (static and SWARM)."""
 
-    # registration batches at least this large take the chunked bulk
-    # overlap path (per-rect loop below it: small batches hit the
-    # incremental GlobalIndex fast path the goldens were frozen on)
-    BULK_INDEX_MIN = 4096
-    _BULK_CHUNK = 131072
-
     def __init__(self, index: GlobalIndex, num_machines: int, **kw):
         super().__init__(num_machines, **kw)
         self.index = index
@@ -315,6 +309,7 @@ class _GridRouter(_Base):
             np.zeros((index.parts.capacity, self.hasher.wildcard + 1),
                      np.float64)
             if self.hasher is not None else None)
+        self.rebuild_query_tables()
         self.store = self._make_store(index.parts.capacity)
 
     def _ensure_qres(self):
@@ -328,70 +323,68 @@ class _GridRouter(_Base):
                  np.zeros((cap - len(self.qres_kw),
                            self.qres_kw.shape[1]), np.float64)])
 
+    def _query_tables(self, rects: np.ndarray, piv: np.ndarray | None):
+        """(pivot buckets present, their corner tables) of ``rects``'
+        cell boxes: one key (0) without keywords."""
+        g = self.index.grid_size
+        keys, inv = np.zeros(1, np.int64), None
+        if piv is not None:
+            seen = np.bincount(piv, minlength=self.hasher.wildcard + 1) > 0
+            keys, inv = np.flatnonzero(seen), (np.cumsum(seen) - 1)[piv]
+        return keys, geometry.corner_tables(
+            *geometry.rects_to_cells(rects, g), g, inv, len(keys))
+
+    def _count(self, tables: np.ndarray, pids: np.ndarray) -> np.ndarray:
+        """(keys, len(pids)) counts of the tables' boxes that overlap
+        each partition in ``pids``."""
+        p = self.index.parts
+        return geometry.count_overlaps(
+            tables, p.r0[pids], p.c0[pids], p.r1[pids], p.c1[pids])
+
+    def rebuild_query_tables(self) -> None:
+        """Bin every standing query (``query_rects``, keyed by
+        ``sub_pivots`` under keywords) into the corner tables afresh —
+        for state set directly, as a checkpoint restore does."""
+        n_keys = 1 if self.hasher is None else self.hasher.wildcard + 1
+        piv = None if self.hasher is None else self.sub_pivots
+        keys, tabs = self._query_tables(self.query_rects, piv)
+        g1 = self.index.grid_size + 1
+        self._qtab = np.zeros((4, n_keys, g1, g1), np.int64)
+        self._qtab[:, keys] = tabs
+
     def _index_queries(self, rects: np.ndarray,
                        terms: np.ndarray | None = None) -> None:
+        """Bin the new batch into the corner tables and add its counts
+        to the live partitions: O(batch + live), whatever the number
+        of standing queries."""
         self._ensure_qres()
         piv = None
         if self.hasher is not None:
             piv = self.hasher.pivots(terms, len(rects))
             self.sub_pivots = np.concatenate([self.sub_pivots, piv])
-        g = self.index.grid_size
-        r0, c0, r1, c1 = geometry.rects_to_cells(rects, g)
-        if len(rects) >= self.BULK_INDEX_MIN:
-            # bulk registration (pub/sub preloads millions of standing
-            # subscriptions): chunked queries × live-partitions overlap
-            # matrix instead of a per-rect Python loop
-            p = self.index.parts
-            live = p.live_ids()
-            lr0, lc0 = p.r0[live][None, :], p.c0[live][None, :]
-            lr1, lc1 = p.r1[live][None, :], p.c1[live][None, :]
-            for lo in range(0, len(rects), self._BULK_CHUNK):
-                hi = min(lo + self._BULK_CHUNK, len(rects))
-                hit = geometry.boxes_overlap(
-                    r0[lo:hi, None], c0[lo:hi, None],
-                    r1[lo:hi, None], c1[lo:hi, None], lr0, lc0, lr1, lc1)
-                self.qres[live] += hit.sum(0)
-                if piv is not None:
-                    qi, li = np.nonzero(hit)
-                    np.add.at(self.qres_kw,
-                              (live[li], piv[lo:hi][qi]), 1.0)
-            return
-        for i in range(len(rects)):
-            pids = self.index.query_overlap_vectorized(
-                int(r0[i]), int(c0[i]), int(r1[i]), int(c1[i]))
-            self.qres[pids] += 1
-            if piv is not None:
-                self.qres_kw[pids, piv[i]] += 1.0
+        keys, tabs = self._query_tables(rects, piv)
+        live = self.index.parts.live_ids()
+        hits = self._count(tabs, live)
+        self.qres[live] += hits.sum(0)
+        if piv is not None:
+            self.qres_kw[live[:, None], keys[None, :]] += hits.T
+        self._qtab[:, keys] += tabs
 
     def reindex_all_queries(self) -> None:
-        """Rebuild per-partition resident counts after a plan change —
-        vectorized partitions × queries overlap test, chunked so
-        million-subscription pub/sub sets never materialize the full
-        Q × P hit matrix."""
-        p = self.index.parts
-        live = p.live_ids()
+        """Rebuild per-partition resident counts after a plan change,
+        from the corner tables the standing queries were binned into at
+        registration: four table reads per live partition (and pivot
+        bucket), exact, in O(live) — no pass over the queries."""
+        live = self.index.parts.live_ids()
         with _tracer().span("reindex_queries", queries=len(self.query_rects),
                             live=len(live)):
             self._ensure_qres()
+            hits = self._count(self._qtab, live)
             self.qres[:] = 0
+            self.qres[live] = hits.sum(0)
             if self.qres_kw is not None:
                 self.qres_kw[:] = 0.0
-            if not len(self.query_rects):
-                return
-            g = self.index.grid_size
-            r0, c0, r1, c1 = geometry.rects_to_cells(self.query_rects, g)
-            lr0, lc0 = p.r0[live][None, :], p.c0[live][None, :]
-            lr1, lc1 = p.r1[live][None, :], p.c1[live][None, :]
-            for lo in range(0, len(self.query_rects), self._BULK_CHUNK):
-                hi = min(lo + self._BULK_CHUNK, len(self.query_rects))
-                hit = geometry.boxes_overlap(
-                    r0[lo:hi, None], c0[lo:hi, None],
-                    r1[lo:hi, None], c1[lo:hi, None], lr0, lc0, lr1, lc1)
-                self.qres[live] += hit.sum(0)
-                if self.qres_kw is not None:
-                    qi, li = np.nonzero(hit)
-                    np.add.at(self.qres_kw,
-                              (live[li], self.sub_pivots[lo:hi][qi]), 1.0)
+                self.qres_kw[live] = hits.T
 
     def _area_frac(self) -> np.ndarray:
         """Partition area as a fraction of the space, per allocated pid

@@ -306,7 +306,7 @@ def test_bulk_subscription_indexing_matches_loop():
     terms = src.sample_subscription_terms(6000, 0, wl.sub_terms)
     bulk = SwarmRouter(32, 4, workload=wl)
     loop = SwarmRouter(32, 4, workload=wl)
-    assert len(rects) >= bulk.BULK_INDEX_MIN
+    assert len(rects) >= 4096
     bulk.register_queries(rects, terms)           # bulk path (one batch)
     for lo in range(0, len(rects), 500):          # loop path (small batches)
         loop.register_queries(rects[lo:lo + 500], terms[lo:lo + 500])
